@@ -93,10 +93,8 @@ class ShardedSignalPlane(FleetSignalPlane):
             )
         self._dhist = self._init_ring_fn(self._dvalues)
         self._offline = np.zeros(self._capacity, bool)
-        self._doffline = jax.device_put(
-            self._offline, fleet_sharding.mask_sharding(self.mesh)
-        )
-        self._mask_dirty = False
+        self._mask_dirty = True
+        self._sync_mask()
         self._hist_len = 1
         # lazily synced host mirrors — the read path is unchanged base code
         self._values = np.asarray(self._dvalues)
@@ -207,8 +205,12 @@ class ShardedSignalPlane(FleetSignalPlane):
         """Upload the offline mask at most once per tick: K ignition
         toggles between steps cost one transfer, not K."""
         if self._mask_dirty:
+            # upload a copy: `set_online` mutates `_offline` in place, and
+            # device_put may read the host buffer after it returns (the
+            # copy is asynchronous), so a later toggle could reach this
+            # tick's mask
             self._doffline = jax.device_put(
-                self._offline, fleet_sharding.mask_sharding(self.mesh)
+                self._offline.copy(), fleet_sharding.mask_sharding(self.mesh)
             )
             self._mask_dirty = False
 

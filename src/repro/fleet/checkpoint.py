@@ -681,21 +681,6 @@ def _snap_plane(plane, codec: _Codec) -> dict:
     }
 
 
-def _reshard_plane(sim, mesh) -> None:
-    """Rebuild the sharded plane on a new mesh; views follow the swap."""
-    from repro.fleet.scenarios import build_plane
-
-    cfg = sim.cfg
-    plane = build_plane(
-        cfg.scenario, cfg.n_clients, cfg.seed,
-        history=cfg.signal_history, plane="sharded", mesh=mesh,
-    )
-    sim.plane = plane
-    sim.pool.plane = plane
-    for v in sim.pool.vehicles.values():
-        v.signals.plane = plane
-
-
 def _restore_plane(sim, s: dict, codec: _Codec, mpath: Path) -> None:
     plane = sim.plane
     n = s["n_clients"]
@@ -733,10 +718,7 @@ def _restore_plane(sim, s: dict, codec: _Codec, mpath: Path) -> None:
         off = np.zeros(cap, dtype=bool)
         off[:n] = offline
         plane._offline = off
-        plane._doffline = jax.device_put(
-            off, fleet_sharding.mask_sharding(plane.mesh)
-        )
-        plane._mask_dirty = False
+        plane._mask_dirty = True  # uploaded (as a copy) at the next step
         plane._hist_len = s["hist_len"]
         plane._values_dirty = True
         plane._hist_dirty = True
@@ -1051,9 +1033,7 @@ class FleetCheckpoint:
         state = manifest["state"]
 
         cfg = _restore_config(state["config"], config_overrides, mpath, mesh)
-        sim = FleetSimulator(cfg)
-        if mesh is not None:
-            _reshard_plane(sim, mesh)
+        sim = FleetSimulator(cfg, mesh=mesh)
         _apply_power_state(sim, state["vehicles"])
         sub_map = _restore_broker(sim.broker, state["broker"], codec, mpath)
         _restore_store(sim.store, state["store"], codec)

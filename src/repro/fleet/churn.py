@@ -40,7 +40,9 @@ def geometric_gap(u: float, p: float) -> int:
     from one uniform draw: the inverse-CDF geometric sample."""
     if p >= 1.0:
         return 1
-    return 1 + int(math.floor(math.log1p(-u) / math.log1p(-p)))
+    # a subnormal p overflows the ratio to inf: cap it at a horizon no
+    # run reaches (exact in f64, so floor() stays well defined)
+    return 1 + int(math.floor(min(math.log1p(-u) / math.log1p(-p), 2.0**53)))
 
 
 class EventChurn:

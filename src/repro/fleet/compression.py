@@ -111,6 +111,12 @@ def make_codec(name: str, **kw) -> Any:
 # --------------------------------------------------------------------- #
 # batched aggregation (the fleet-scale hot path)                         #
 # --------------------------------------------------------------------- #
+#: full f32 contraction: at default precision the TPU rounds the f32
+#: per-row scales to bf16 before the matmul, which misses the per-client
+#: reference (`rounds.aggregate_reference`) by far more than 1e-6
+_F32 = jax.lax.Precision.HIGHEST
+
+
 @jax.jit
 def _dequant_weighted_sum(
     q: jax.Array,  # (N, R, row) int8 — all clients' packed deltas, stacked
@@ -126,14 +132,16 @@ def _dequant_weighted_sum(
     XLA fuses the int8->f32 cast straight into the reduction and never
     materializes the (N, R, row) f32 dequantized tensor."""
     ws = w[:, None] * s
-    return jnp.einsum("nr,nrc->rc", ws, q.astype(jnp.float32))
+    return jnp.einsum(
+        "nr,nrc->rc", ws, q.astype(jnp.float32), precision=_F32
+    )
 
 
 @jax.jit
 def _dequant_mean_uniform(q: jax.Array, s: jax.Array) -> jax.Array:
     """Unweighted FedAvg mean: the 1/N weight is a compile-time scalar, so
     no weight vector is built or transferred per round."""
-    out = jnp.einsum("nr,nrc->rc", s, q.astype(jnp.float32))
+    out = jnp.einsum("nr,nrc->rc", s, q.astype(jnp.float32), precision=_F32)
     return out / q.shape[0]
 
 

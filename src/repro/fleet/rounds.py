@@ -244,16 +244,18 @@ def aggregate_reference(
     msgs: list[dict[str, Any]], weights: np.ndarray | None = None
 ) -> np.ndarray:
     """The pre-vectorization per-client loop, kept as the correctness
-    oracle and the benchmark baseline (`benchmarks/fleet_scale.py`)."""
+    oracle and the benchmark baseline (`benchmarks/fleet_scale.py`).
+    It accumulates in float64: an f32 running sum drifts by more than
+    1e-6 over a 100k-vehicle round (3.1e-6 measured)."""
     deltas = [unpack_delta(m) for m in msgs]
     if weights is None:
-        return np.mean(np.stack(deltas), axis=0)
+        return np.mean(np.stack(deltas), axis=0, dtype=np.float64).astype(np.float32)
     w = np.asarray(weights, np.float32)
     w = w / w.sum()
-    out = np.zeros_like(deltas[0])
+    out = np.zeros(deltas[0].shape, np.float64)
     for d, wi in zip(deltas, w):
         out += wi * d
-    return out
+    return out.astype(np.float32)
 
 
 #: Payload template executed inside every vehicle's task container.

@@ -224,7 +224,10 @@ class FleetSimulator:
         cfg: SimConfig,
         *,
         signal_fn: Callable[[int], dict] | None = None,
+        mesh=None,
     ):
+        """`mesh` places the sharded plane on a `clients` device mesh
+        (`repro.sharding.fleet.client_mesh`; default: every device)."""
         self.cfg = cfg
         b = cfg.backends
         faults = seeded_fault_plan(
@@ -260,6 +263,7 @@ class FleetSimulator:
                 cfg.seed,
                 history=cfg.signal_history,
                 plane=b.plane.value,
+                mesh=mesh,
             )
         )
         self.pool = FleetPool(

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -276,12 +275,13 @@ def _sketch_kernel(x_ref, xs_ref, e_ref, o_ref, *, quantile_k: int, n_bins: int)
     Xs = xs_ref[...]  # (W, bn) same block, sorted along the window axis
     W, bn = X.shape
     valid = jnp.logical_not(jnp.isnan(X))
-    Xz = jnp.where(valid, X, 0.0)
 
     def body(s, carry):
-        v = jax.lax.dynamic_index_in_dim(Xz, s, 0, keepdims=False)
-        ok = jax.lax.dynamic_index_in_dim(valid, s, 0, keepdims=False)
-        return _welford_update(carry, v, ok)
+        # Row s is read from the ref: Mosaic lowers a dynamic ref slice
+        # but not a dynamic_slice of a loaded value.
+        x = x_ref[pl.ds(s, 1), :][0]
+        ok = jnp.logical_not(jnp.isnan(x))
+        return _welford_update(carry, jnp.where(ok, x, 0.0), ok)
 
     zeros = jnp.zeros((bn,), jnp.float32)
     c, m, m2, pend = jax.lax.fori_loop(0, W, body, (zeros, zeros, zeros, zeros))
@@ -351,12 +351,12 @@ def _pallas_fold_fn(mesh, quantile_k, n_bins, block_clients, interpret):
     if mesh is None:
         return base
     axis = mesh.axis_names[0]
-    return shard_map(
+    return jax.shard_map(
         base,
         mesh=mesh,
         in_specs=(P(None, axis), P(None)),
         out_specs=P(None, axis),
-        check_rep=False,  # no replication rule for pallas_call
+        check_vma=False,  # no varying-axes rule for pallas_call
     )
 
 

@@ -30,6 +30,7 @@ from repro.fleet.federated import FedConfig
 from repro.fleet.metrics import RoundMetrics
 from repro.fleet.scenarios import PLANES, SCENARIOS
 from repro.fleet.simulator import Backends, FleetSimulator, SimConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -207,6 +208,7 @@ def _resume(ap: argparse.ArgumentParser, args) -> None:
 def main() -> None:
     ap = build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
     if args.restore_from is not None:
         _resume(ap, args)
         return

@@ -22,6 +22,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -60,9 +62,10 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def _fleet_main(args: argparse.Namespace) -> None:
+def build_fleet(args: argparse.Namespace, *, mesh=None):
+    """Boot the `FleetSimulator` the gateway fronts and run its warm-up
+    ticks. `mesh` places the sharded plane (default: every device)."""
     from repro.fleet.simulator import Backends, FleetSimulator, SimConfig
-    from repro.serve.gateway import FleetGateway
 
     sim = FleetSimulator(
         SimConfig(
@@ -73,16 +76,24 @@ def _fleet_main(args: argparse.Namespace) -> None:
             p_return=args.p_return,
             straggler_fraction=args.stragglers,
             backends=Backends(plane=args.plane),
-        )
+        ),
+        mesh=mesh,
     )
     for _ in range(args.warmup_ticks):
         sim.tick()
-    gw = FleetGateway(sim, admit_per_tick=args.admit_per_tick)
+    return sim
 
+
+def replay_mix(sim, args: argparse.Namespace):
+    """Open `--sessions` analyst sessions on `sim`, replay the request mix
+    until every request is served, and return ``(gateway, responses in
+    seq order, ticks used)``."""
+    from repro.serve.gateway import FleetGateway
+
+    gw = FleetGateway(sim, admit_per_tick=args.admit_per_tick)
     # the deterministic request mix every session replays: a dashboard
     # poll, fleet-level statistics, a percentile query, then the
     # submissions — all in flight concurrently across sessions
-    t0 = time.perf_counter()
     for s in range(args.sessions):
         sess = gw.session(f"analyst-{s}")
         sess.gauges()
@@ -94,19 +105,29 @@ def _fleet_main(args: argparse.Namespace) -> None:
         for _ in range(args.windows):
             sess.submit_window(args.signal, sketch=True)
     ticks = gw.run_until_idle()
-    wall = time.perf_counter() - t0
-
     responses = [r for s in gw._sessions.values() for r in s.inbox]
     responses.sort(key=lambda r: r.seq)
+    return gw, responses, ticks
+
+
+def _fleet_main(args: argparse.Namespace) -> int:
+    """Serve the mix and print every response; exit status 1 if any
+    request was answered with ``ok=False``."""
+    sim = build_fleet(args)
+    t0 = time.perf_counter()
+    _, responses, ticks = replay_mix(sim, args)
+    wall = time.perf_counter() - t0
     for r in responses:
         print(r.encode().decode())
     lat = np.asarray([r.ticks for r in responses], np.float64)
+    failed = sum(not r.ok for r in responses)
     print(
-        f"-- {len(responses)} responses over {ticks} ticks "
+        f"-- {len(responses)} responses ({failed} failed) over {ticks} ticks "
         f"({len(responses) / max(wall, 1e-9):.0f} resp/s wall); "
         f"response ticks p50={np.percentile(lat, 50):.0f} "
         f"p99={np.percentile(lat, 99):.0f}"
     )
+    return 1 if failed else 0
 
 
 def _llm_main(args: argparse.Namespace) -> None:
@@ -137,13 +158,14 @@ def _llm_main(args: argparse.Namespace) -> None:
         print(rid, results[rid])
 
 
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.llm:
         _llm_main(args)
-    else:
-        _fleet_main(args)
+        return 0
+    return _fleet_main(args)
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    sys.exit(main(sys.argv[1:]))

@@ -555,6 +555,7 @@ class FleetGateway:
             "round": rec["round"],
             "participants": rec["participants"],
             "canceled": rec["canceled"],
+            "errors": inf.rif.assign.counts().error,
             "pumps": rec["pumps"],
             "mean_client_loss": rec["mean_client_loss"],
             "dist_to_optimum": rec["dist_to_optimum"],
@@ -591,6 +592,7 @@ class FleetGateway:
             "window_id": rec.window_id,
             "participants": rec.participants,
             "canceled": rec.canceled,
+            "errors": inf.rif.assign.counts().error,
             "pumps": rec.pumps,
             "count": rec.count,
             "mean": None if np.isnan(rec.mean) else float(rec.mean),

@@ -75,6 +75,8 @@ def test_geometric_gap_inverse_cdf():
     assert geometric_gap(0.51, 0.5) == 2
     # tiny p gives long horizons, never zero or negative
     assert geometric_gap(0.5, 0.001) >= 1
+    # a subnormal p (a hypothesis draw) is "never", not an OverflowError
+    assert geometric_gap(0.5, 2.225073858507203e-309) == 1 + 2**53
 
 
 def test_make_churn_selects_and_rejects():

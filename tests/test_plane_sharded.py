@@ -69,6 +69,20 @@ def test_offline_nan_masking_matches_host_plane():
     assert np.array_equal(host.values, sharded.values)
 
 
+def test_uploaded_offline_mask_ignores_later_toggles():
+    """The mask a step was given is a snapshot of the host mask at that
+    step: toggles after it must not reach it. (device_put copies host
+    memory asynchronously, so an in-place toggle right after a step could
+    land in that step's mask: the golden sharded restore test flaked.)"""
+    plane = Scenario("mixed", 0).sharded_plane(1 << 20, history=2)
+    for trial in range(8):
+        plane.set_online(0, bool(trial % 2))
+        plane.step()
+        want = plane._offline.copy()
+        plane._offline[1:] = not plane._offline[1]  # every row toggles
+        assert np.array_equal(np.asarray(plane._doffline), want), trial
+
+
 def test_plane_signal_view_and_handler_work_unchanged():
     """`autospada.get_signal` / `get_signal_window` plumbing: the same
     SignalHandler-over-PlaneSignalView stack reads the sharded plane."""

@@ -8,8 +8,8 @@ from repro.configs import get_config
 from repro.models.model import cache_spec, init_params
 from repro.sharding import planner
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def shapes_of(arch):

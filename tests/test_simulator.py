@@ -12,7 +12,7 @@ from repro.fleet import (
     aggregate_reference,
     stack_deltas,
 )
-from repro.fleet.rounds import pack_delta
+from repro.fleet.rounds import pack_delta, unpack_delta
 
 
 # --------------------------------------------------------------------- #
@@ -78,6 +78,15 @@ def test_batched_aggregation_matches_reference():
     assert np.allclose(
         aggregate_packed(msgs, w), aggregate_reference(msgs, w), atol=1e-6
     )
+
+
+def test_reference_aggregate_does_not_drift_over_many_clients():
+    """The oracle must hold to 1e-6 where the batched path is held to it:
+    an f32 running sum over thousands of equal-weight clients drifted."""
+    d = np.linspace(-0.5, 0.5, 256).astype(np.float32)
+    msg = pack_delta(d, row=256)
+    got = aggregate_reference([msg] * 3000, np.ones(3000, np.float32))
+    assert np.abs(got - unpack_delta(msg)).max() < 1e-7
 
 
 def test_heterogeneous_shapes_fall_back_to_reference():

@@ -1,0 +1,102 @@
+"""Ahead-of-time compiles of the fleet path's device programs for a
+described TPU v5e (no chip attached).
+
+The TPU compiler refuses what interpret mode accepts (unsupported Mosaic
+primitives, unaligned slices, programs that do not fit device memory),
+so these compiles guard the sketch kernel at its serving widths on every
+test run. The topology is described inside a module-scoped fixture, never
+at import: only one process at a time may load the TPU library, and the
+tests of this file must be the same on every pytest-xdist worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.kernels import sketch as sk
+from repro.sharding.fleet import CLIENT_AXIS
+
+SPEC = sk.SketchSpec()  # window 64, 16 bins, K=32: the gateway default
+#: the serving ring: 256 history slots x 100k vehicles (rounded up to a
+#: 128-client block multiple) x the mixed scenario's 4 signals
+HISTORY, CLIENTS, SIGNALS = 256, 102_400, 4
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # described-device compiles are written to a persistent cache but can
+    # never be read back without a chip: keep the cache out of the way
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(topo.devices[:4], (CLIENT_AXIS,))
+
+
+def _edges(sharding):
+    return jax.ShapeDtypeStruct((SPEC.bins - 1,), jnp.float32, sharding=sharding)
+
+
+@pytest.mark.parametrize("window,clients", [(64, 4096), (256, CLIENTS)])
+def test_pallas_fold_compiles_for_one_chip(one_chip, window, clients):
+    x = jax.ShapeDtypeStruct((window, clients), jnp.float32, sharding=one_chip)
+    compiled = sk._fold_pallas.lower(
+        x, _edges(one_chip),
+        quantile_k=SPEC.quantile_k, n_bins=SPEC.bins,
+        block_clients=128, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_pallas_fold_compiles_for_four_chips(four_chips):
+    fold = sk._pallas_fold_fn(four_chips, SPEC.quantile_k, SPEC.bins, 128, False)
+    x = jax.ShapeDtypeStruct(
+        (SPEC.window, CLIENTS), jnp.float32,
+        sharding=NamedSharding(four_chips, P(None, CLIENT_AXIS)),
+    )
+    edges = _edges(NamedSharding(four_chips, P(None)))
+    text = jax.jit(fold).lower(x, edges).compile().as_text()
+    assert "tpu_custom_call" in text
+    # each chip folds its own client columns: nothing crosses chips
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
+        assert op not in text
+
+
+def test_ring_sketch_twin_fits_one_chip(one_chip):
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ring = jax.ShapeDtypeStruct(
+        (HISTORY, CLIENTS, SIGNALS), jnp.float32, sharding=one_chip
+    )
+    compiled = sk._ring_sketch_xla.lower(
+        ring, scalar, scalar, _edges(one_chip),
+        col=0, window=SPEC.window, quantile_k=SPEC.quantile_k,
+    ).compile()
+    mem = compiled.memory_analysis()
+    used = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+    assert mem.argument_size_in_bytes >= HISTORY * CLIENTS * SIGNALS * 4
+    assert used < V5E_HBM_BYTES
