@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.core import obs
 from repro.core.signals import FleetSignalPlane
 from repro.sharding import fleet as fleet_sharding
 
@@ -102,9 +103,14 @@ class ShardedSignalPlane(FleetSignalPlane):
         self._values_dirty = False
         self._hist_dirty = False
         self._sketch_cache: dict = {}
+        self.sketch_hits = 0
+        self.sketch_misses = 0
         #: device->host ring transfers so far — the sketch path must
         #: never bump it (asserted in the fleet/sketch_* benchmark)
         self.ring_syncs = 0
+        #: bytes of every device->host copy the plane makes (ring and
+        #: values mirrors, sketch blocks)
+        self.d2h_bytes = 0
 
     @property
     def devices(self) -> int:
@@ -194,12 +200,15 @@ class ShardedSignalPlane(FleetSignalPlane):
         if self._values_dirty:
             self._values = np.asarray(self._dvalues)
             self._values_dirty = False
+            self.d2h_bytes += self._values.nbytes
 
     def _sync_hist(self) -> None:
         if self._hist_dirty:
-            self._hist = np.asarray(self._dhist)
+            with obs.span("plane.ring_sync"):
+                self._hist = np.asarray(self._dhist)
             self._hist_dirty = False
             self.ring_syncs += 1
+            self.d2h_bytes += self._hist.nbytes
 
     def _sync_mask(self) -> None:
         """Upload the offline mask at most once per tick: K ignition
@@ -228,27 +237,28 @@ class ShardedSignalPlane(FleetSignalPlane):
         CSV-fed planes (`from_csv_fleet`) pull the next streamed host
         row instead, pad it to capacity, and run the same donated ring
         write — one host->device transfer per tick, never a trace."""
-        self.t += 1
-        self._sync_mask()
-        if self._feed is not None:
-            row = self._feed.series(self.t)
-            padded = np.full(
-                (self._capacity, len(self.names)), np.nan, np.float32
-            )
-            padded[: row.shape[0]] = row
-            drow = jax.device_put(
-                padded, fleet_sharding.values_sharding(self.mesh)
-            )
-            self._dvalues, self._dhist = self._feed_fn(
-                jnp.int32(self.t), drow, self._dhist, self._doffline
-            )
-        else:
-            self._dvalues, self._dhist = self._tick_fn(
-                jnp.int32(self.t), self._dhist, self._doffline
-            )
-        self._hist_len = min(self._hist_len + 1, self._hist_cap)
-        self._values_dirty = True
-        self._hist_dirty = True
+        with obs.span("plane.step"):
+            self.t += 1
+            self._sync_mask()
+            if self._feed is not None:
+                row = self._feed.series(self.t)
+                padded = np.full(
+                    (self._capacity, len(self.names)), np.nan, np.float32
+                )
+                padded[: row.shape[0]] = row
+                drow = jax.device_put(
+                    padded, fleet_sharding.values_sharding(self.mesh)
+                )
+                self._dvalues, self._dhist = self._feed_fn(
+                    jnp.int32(self.t), drow, self._dhist, self._doffline
+                )
+            else:
+                self._dvalues, self._dhist = self._tick_fn(
+                    jnp.int32(self.t), self._dhist, self._doffline
+                )
+            self._hist_len = min(self._hist_len + 1, self._hist_cap)
+            self._values_dirty = True
+            self._hist_dirty = True
 
     def block_until_ready(self) -> None:
         """Wait for in-flight device work (benchmark fairness hook)."""
@@ -276,11 +286,13 @@ class ShardedSignalPlane(FleetSignalPlane):
         n = self.n_clients
         if col is None or n == 0:
             return _sk.empty_fleet_sketches(spec, n)
-        out = _sk.sketch_ring(
-            self._dhist, self.t, self._hist_len, col, spec,
-            backend=backend, mesh=self.mesh,
-        )
-        return _sk.sketches_from_device(spec, np.asarray(out)[:, :n])
+        with obs.span("plane.fold"):
+            out = np.asarray(_sk.sketch_ring(
+                self._dhist, self.t, self._hist_len, col, spec,
+                backend=backend, mesh=self.mesh,
+            ))
+        self.d2h_bytes += out.nbytes
+        return _sk.sketches_from_device(spec, out[:, :n])
 
     def set_online(self, row: int, online: bool) -> None:
         super().set_online(row, online)

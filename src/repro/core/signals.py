@@ -46,6 +46,8 @@ from typing import Callable, Iterable, Iterator, Mapping, Sequence
 
 import numpy as np
 
+from repro.core import obs
+
 
 class SignalBroker:
     """Pub/sub source of (signal_name, value) observations."""
@@ -318,6 +320,9 @@ class FleetSignalPlane:
         # one fleet-wide sketch per (tick, fleet size, signal, spec) —
         # see sketch_row
         self._sketch_cache: dict = {}
+        #: `fleet_sketch` answers from that cache (hits) or by a fold
+        self.sketch_hits = 0
+        self.sketch_misses = 0
 
     @property
     def values(self) -> np.ndarray:
@@ -392,13 +397,14 @@ class FleetSignalPlane:
         ring write. This is the whole fleet's per-tick signal cost.
         Offline rows are NaN-masked in the ring (not in `values`): a
         powered-off vehicle observes nothing while the ignition is off."""
-        self.t += 1
-        self._values = np.asarray(self._series_fn(self.t), np.float32)
-        slot = self.t % self._hist_cap
-        self._hist[slot] = self._values
-        if self._offline.any():
-            self._hist[slot, self._offline] = np.nan
-        self._hist_len = min(self._hist_len + 1, self._hist_cap)
+        with obs.span("plane.step"):
+            self.t += 1
+            self._values = np.asarray(self._series_fn(self.t), np.float32)
+            slot = self.t % self._hist_cap
+            self._hist[slot] = self._values
+            if self._offline.any():
+                self._hist[slot, self._offline] = np.nan
+            self._hist_len = min(self._hist_len + 1, self._hist_cap)
 
     def _check_row(self, row: int) -> int:
         """Spare capacity rows hold real scenario values (step computes the
@@ -460,11 +466,12 @@ class FleetSignalPlane:
         n = self.n_clients
         if col is None or n == 0:
             return _sk.empty_fleet_sketches(spec, n)
-        out = _sk.sketch_ring(
-            jnp.asarray(self._hist), self.t, self._hist_len, col, spec,
-            backend=backend,
-        )
-        return _sk.sketches_from_device(spec, np.asarray(out)[:, :n])
+        with obs.span("plane.fold"):
+            out = np.asarray(_sk.sketch_ring(
+                jnp.asarray(self._hist), self.t, self._hist_len, col, spec,
+                backend=backend,
+            ))
+        return _sk.sketches_from_device(spec, out[:, :n])
 
     def fleet_sketch(self, name: str, spec):
         """The fleet-wide sketch fold, served from the per-tick cache:
@@ -477,10 +484,13 @@ class FleetSignalPlane:
         need to)."""
         key = (self.t, self.n_clients, name, spec)
         sk = self._sketch_cache.get(key)
-        if sk is None:
-            self._sketch_cache.clear()
-            sk = self.compute_sketches(name, spec)
-            self._sketch_cache[key] = sk
+        if sk is not None:
+            self.sketch_hits += 1
+            return sk
+        self.sketch_misses += 1
+        self._sketch_cache.clear()
+        sk = self.compute_sketches(name, spec)
+        self._sketch_cache[key] = sk
         return sk
 
     def sketch_row(self, row: int, name: str, spec) -> dict:

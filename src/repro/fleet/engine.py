@@ -30,6 +30,7 @@ faults × churn × stragglers grid.
 from __future__ import annotations
 
 import heapq
+from repro.core import obs
 from repro.core.counter import Counter
 import threading
 from collections import deque
@@ -256,32 +257,33 @@ class EventEngine:
         self.draining = True
         fired = 0
         heap = self._heap
-        try:
-            # overdue entries, this tick's gateway admissions, and this
-            # tick's churn toggles first: lane membership must reflect
-            # every power transition at tick t
-            while heap and (
-                heap[0][0] < t or (heap[0][0] == t and heap[0][1] <= PHASE_CHURN)
-            ):
-                entry = heapq.heappop(heap)[4]
-                if entry.canceled:
-                    continue
-                entry.fired = True
-                if entry.fn is not None:
-                    entry.fn()
-                fired += 1
-            for lane in self._lanes:
-                fired += lane.fire(t)
-            while heap and heap[0][0] <= t:
-                entry = heapq.heappop(heap)[4]
-                if entry.canceled:
-                    continue
-                entry.fired = True
-                if entry.fn is not None:
-                    entry.fn()
-                fired += 1
-        finally:
-            self.draining = False
+        with obs.span("engine.drain"):
+            try:
+                # overdue entries, this tick's gateway admissions, and this
+                # tick's churn toggles first: lane membership must reflect
+                # every power transition at tick t
+                while heap and (
+                    heap[0][0] < t or (heap[0][0] == t and heap[0][1] <= PHASE_CHURN)
+                ):
+                    entry = heapq.heappop(heap)[4]
+                    if entry.canceled:
+                        continue
+                    entry.fired = True
+                    if entry.fn is not None:
+                        entry.fn()
+                    fired += 1
+                for lane in self._lanes:
+                    fired += lane.fire(t)
+                while heap and heap[0][0] <= t:
+                    entry = heapq.heappop(heap)[4]
+                    if entry.canceled:
+                        continue
+                    entry.fired = True
+                    if entry.fn is not None:
+                        entry.fn()
+                    fired += 1
+            finally:
+                self.draining = False
         return fired
 
     def __len__(self) -> int:

@@ -55,6 +55,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.broker import Broker, seeded_fault_plan
 from repro.core.columns import FleetColumns, deep_sizeof
 from repro.core.server import make_platform
@@ -379,7 +380,8 @@ class FleetSimulator:
         self.pool.tick_signals(online_only=True)
         # 4. bounded sync-loop service: O(runnable) via the event-driven
         #    scheduler (or the dense O(N) oracle — identical interleaving)
-        self.service.tick(self.t)
+        with obs.span("service.sweep"):
+            self.service.tick(self.t)
 
     # `pump` alias: FederatedDriver and AssignmentDoc.await_results take a
     # zero-arg world-advancer

@@ -56,6 +56,7 @@ from typing import TYPE_CHECKING, Any
 
 import numpy as np
 
+from repro.core import obs
 from repro.fleet.analytics import AnalyticsConfig, AnalyticsDriver
 from repro.fleet.engine import PHASE_ADMIT
 from repro.fleet.federated import FedConfig
@@ -274,6 +275,11 @@ class FleetGateway:
         self._stats_cache: dict = {}
         #: served-request counters by kind (observability, not behavior)
         self.served: dict[str, int] = {}
+        #: requests admitted, and statistics reads answered from the
+        #: per-tick merged-stats cache (hits) or computed anew (misses)
+        self.admitted = 0
+        self.stats_hits = 0
+        self.stats_misses = 0
 
     # ------------------------------------------------------------------ #
     # the request plane                                                  #
@@ -296,6 +302,7 @@ class FleetGateway:
             submitted_tick=self.sim.t,
         )
         self._seq += 1
+        obs.stamp_submit(req.seq, kind)
         ticket = Ticket(req)
         self._pending.append(ticket)
         self._arm()
@@ -318,13 +325,15 @@ class FleetGateway:
         n = len(self._pending) if budget is None else min(
             budget, len(self._pending)
         )
-        for _ in range(n):
-            ticket = self._pending.popleft()
-            self._dispatch(ticket)
+        with obs.span("gateway.admit"):
+            for _ in range(n):
+                self._dispatch(self._pending.popleft())
+        self.admitted += n
         self._arm()  # backpressure: anything left waits for the next tick
 
     def _dispatch(self, ticket: Ticket) -> None:
         req = ticket.request
+        obs.stamp_admit(req.seq)
         try:
             if req.kind in READ_KINDS:
                 body = getattr(self, f"_read_{req.kind}")(req.params)
@@ -349,6 +358,7 @@ class FleetGateway:
             body=body,
         )
         ticket.response = resp
+        obs.stamp_done(req.seq)
         self._sessions[req.session].inbox.append(resp)
         self.served[req.kind] = self.served.get(req.kind, 0) + 1
 
@@ -359,8 +369,9 @@ class FleetGateway:
         """One world step: the engine drain admits queued requests at the
         boundary, the simulator ticks, then every in-flight submission
         gets exactly one no-pump quorum/deadline check."""
-        self.sim.tick()
-        self._settle()
+        with obs.span("gateway.tick"):
+            self.sim.tick()
+            self._settle()
 
     def _settle(self) -> None:
         if not self._inflight:
@@ -471,24 +482,28 @@ class FleetGateway:
         key = (plane.t, plane.n_clients, signal, spec)
         st = self._stats_cache.get(key)
         if st is not None:
+            self.stats_hits += 1
             return st
+        self.stats_misses += 1
         self._stats_cache.clear()
-        sk = plane.fleet_sketch(signal, spec)
-        counts = sk.counts.astype(np.float32)
-        c, mean, m2 = merge_moments(counts, sk.means, sk.m2s)
-        hist = merge_histograms(sk.hists)
-        qv = qw = None
-        if c > 0:
-            qv, qw = merge_quantile_sketches(sk.qvals, counts)
-        st = _FleetStats(
-            participants=int(np.count_nonzero(sk.counts)),
-            count=int(c),
-            mean=float(mean) if c > 0 else None,
-            var=float(m2 / c) if c > 0 else None,
-            hist=tuple(int(v) for v in hist),
-            qv=qv,
-            qw=qw,
-        )
+        with obs.span("gateway.stats"):
+            sk = plane.fleet_sketch(signal, spec)
+            counts = sk.counts.astype(np.float32)
+            with obs.span("gateway.merge"):
+                c, mean, m2 = merge_moments(counts, sk.means, sk.m2s)
+                hist = merge_histograms(sk.hists)
+                qv = qw = None
+                if c > 0:
+                    qv, qw = merge_quantile_sketches(sk.qvals, counts)
+            st = _FleetStats(
+                participants=int(np.count_nonzero(sk.counts)),
+                count=int(c),
+                mean=float(mean) if c > 0 else None,
+                var=float(m2 / c) if c > 0 else None,
+                hist=tuple(int(v) for v in hist),
+                qv=qv,
+                qw=qw,
+            )
         self._stats_cache[key] = st
         return st
 
