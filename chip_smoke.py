@@ -179,19 +179,26 @@ def kernel_phase(plane, signal: str) -> None:
     spec = sk.SketchSpec()
     interpret = not sk._on_tpu()
     _require(not interpret, "sketch fold would run in interpret mode")
-    fold = sk._pallas_fold_fn(plane.mesh, spec.quantile_k, spec.bins, 128, interpret)
-    x = sk._ring_window(plane._dhist, plane.t, plane._hist_len,
-                        col=plane._col[signal], window=spec.window)
-    edges = jax.numpy.asarray(spec.edges())
-    text = jax.jit(fold).lower(x, edges).as_text()
+    # the program `sketch_ring` runs for this plane: ring gather and
+    # shard_mapped kernel in one jit
+    args = (plane._dhist, jax.numpy.int32(plane.t), jax.numpy.int32(plane._hist_len),
+            jax.numpy.asarray(spec.edges()))
+    statics = dict(mesh=plane.mesh, col=plane._col[signal], window=spec.window,
+                   quantile_k=spec.quantile_k, n_bins=spec.bins, interpret=interpret)
+    text = sk._ring_sketch_pallas.lower(*args, **statics).as_text()
     _require("tpu_custom_call" in text, "no tpu_custom_call in the lowered fold")
-    out = sk.sketches_from_device(spec, np.asarray(fold(x, edges))[:, : plane.n_clients])
+    traces = sk.fold_traces
+    out = np.asarray(sk._ring_sketch_pallas(*args, **statics))[:, : plane.n_clients]
     served = plane.fleet_sketch(signal, spec)
+    _require(sk.fold_traces == traces, "the plane's fold built its program again")
+    out = sk.sketches_from_device(spec, out)
     for f in ("counts", "means", "m2s", "hists", "qvals"):
         _require(np.array_equal(getattr(out, f), getattr(served, f), equal_nan=True),
                  f"the kernel's {f} are not what the plane served")
     _say(f"kernel: fold lowered with tpu_custom_call, interpret={interpret}, "
-         f"over a {x.shape} window on {len(plane.mesh.devices.flat)} device(s)")
+         f"over a {spec.window}-slot window of a {plane._dhist.shape} ring on "
+         f"{len(plane.mesh.devices.flat)} device(s); {sk.fold_traces} fold "
+         f"program(s) built in this process")
 
 
 def one_chip(opts) -> None:

@@ -337,37 +337,47 @@ def _fold_pallas(x, edges, *, quantile_k, n_bins, block_clients, interpret):
     return out[:, :n]
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_fold_fn(mesh, quantile_k, n_bins, block_clients, interpret):
-    """The Pallas fold, shard_mapped over the client axis when the ring
-    lives on a mesh — each device folds only its own client columns."""
-    base = functools.partial(
+#: Programs built for the Pallas ring fold: incremented by
+#: `_ring_sketch_pallas`'s Python body, which runs only while jit traces
+#: it, so it counts builds (one per static key and shape), not calls.
+fold_traces = 0
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("mesh", "col", "window", "quantile_k", "n_bins", "interpret"),
+)
+def _ring_sketch_pallas(
+    ring, t, hist_len, edges, *, mesh, col, window, quantile_k, n_bins, interpret
+):
+    """Fused gather + Pallas fold, one program per static key. With a
+    mesh the fold is shard_mapped over the client axis: each device
+    folds only its own client columns."""
+    global fold_traces
+    fold_traces += 1
+    x = _window_block(ring, t, hist_len, col, window)
+    fold = functools.partial(
         _fold_pallas,
         quantile_k=quantile_k,
         n_bins=n_bins,
-        block_clients=block_clients,
+        block_clients=128,
         interpret=interpret,
     )
-    if mesh is None:
-        return base
-    axis = mesh.axis_names[0]
-    return jax.shard_map(
-        base,
-        mesh=mesh,
-        in_specs=(P(None, axis), P(None)),
-        out_specs=P(None, axis),
-        check_vma=False,  # no varying-axes rule for pallas_call
-    )
+    if mesh is not None:
+        axis = mesh.axis_names[0]
+        fold = jax.shard_map(
+            fold,
+            mesh=mesh,
+            in_specs=(P(None, axis), P(None)),
+            out_specs=P(None, axis),
+            check_vma=False,  # no varying-axes rule for pallas_call
+        )
+    return fold(x, edges)
 
 
 # --------------------------------------------------------------------- #
 # dispatch                                                              #
 # --------------------------------------------------------------------- #
-@functools.partial(jax.jit, static_argnames=("col", "window"))
-def _ring_window(ring, t, hist_len, *, col, window):
-    return _window_block(ring, t, hist_len, col, window)
-
-
 def fold_window(x, spec: SketchSpec, *, backend: str | None = None):
     """Fold a (W, n) time-ordered window matrix (NaN = not observed)
     into `(spec.dim, n)` f32 sketches. Kernel-level entry used by the
@@ -416,6 +426,8 @@ def sketch_ring(
         )
     if backend != "pallas":
         raise ValueError(f"unknown sketch backend {backend!r}")
-    x = _ring_window(ring, t, hist_len, col=col, window=spec.window)
-    fold = _pallas_fold_fn(mesh, spec.quantile_k, spec.bins, 128, not _on_tpu())
-    return fold(x, edges)
+    return _ring_sketch_pallas(
+        ring, t, hist_len, edges,
+        mesh=mesh, col=col, window=spec.window, quantile_k=spec.quantile_k,
+        n_bins=spec.bins, interpret=not _on_tpu(),
+    )

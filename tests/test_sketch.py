@@ -15,6 +15,7 @@ import pytest
 from repro.fleet.analytics import AnalyticsConfig, WindowStats
 from repro.fleet.scenarios import Scenario
 from repro.fleet.simulator import FleetSimulator, SimConfig
+from repro.kernels import sketch as sk
 from repro.kernels.ops import merge_quantile_sketches
 from repro.kernels.sketch import (
     FleetSketches,
@@ -147,18 +148,47 @@ def test_sharded_matches_host_and_ring_stays_on_device():
     assert np.array_equal(hs.qvals, ss.qvals, equal_nan=True)
 
 
-def test_sharded_pallas_backend_matches_xla():
-    """The shard_mapped Pallas kernel (interpret mode off-TPU) agrees
-    with the sharding-propagated XLA twin on every shard."""
+@pytest.mark.parametrize(
+    "steps,windows",
+    [(10, (8,)), (3, (8,)), (40, (8,)), (40, (4, 16))],
+    ids=["steady", "short-history", "wrapped-ring", "two-windows"],
+)
+def test_sharded_pallas_backend_matches_xla(steps, windows):
+    """The fused, shard_mapped Pallas program (interpret mode off-TPU)
+    agrees with the sharding-propagated XLA twin on every shard: with
+    less history than the window, after the 16-slot ring has wrapped,
+    and at two windows over one ring."""
     shard = Scenario("highway", seed=6).sharded_plane(24, history=16)
-    for _ in range(10):
+    for _ in range(steps):
         shard.step()
-    spec = SketchSpec(window=8, quantile_k=8)
-    a = shard.compute_sketches(SIG, spec, backend="xla")
-    b = shard.compute_sketches(SIG, spec, backend="pallas")
-    for field in ("counts", "means", "m2s", "hists"):
-        assert np.array_equal(getattr(a, field), getattr(b, field)), field
-    assert np.array_equal(a.qvals, b.qvals, equal_nan=True)
+    for window in windows:
+        spec = SketchSpec(window=window, quantile_k=8)
+        a = shard.compute_sketches(SIG, spec, backend="xla")
+        b = shard.compute_sketches(SIG, spec, backend="pallas")
+        for field in ("counts", "means", "m2s", "hists"):
+            assert np.array_equal(getattr(a, field), getattr(b, field)), field
+        assert np.array_equal(a.qvals, b.qvals, equal_nan=True)
+
+
+def test_sharded_pallas_fold_builds_once_per_signal_and_window():
+    """The Pallas ring fold is one jitted program per static key: new
+    ticks and new bin edges reuse it, only a new window builds another.
+    The spec's bins, K and the fleet size are this test's own, so no
+    other test has built these programs before."""
+    shard = Scenario("highway", seed=9).sharded_plane(19, history=16)
+    for _ in range(4):
+        shard.step()
+    before = sk.fold_traces
+    for i in range(3):
+        spec = SketchSpec(window=6, bins=5, quantile_k=3, lo=float(i), hi=9.0 + i)
+        got = shard.compute_sketches(SIG, spec, backend="pallas")
+        want = shard.compute_sketches(SIG, spec, backend="xla")
+        assert np.array_equal(got.hists, want.hists)  # the edges are not baked in
+        shard.step()
+    assert sk.fold_traces - before == 1
+    shard.compute_sketches(SIG, SketchSpec(window=12, bins=5, quantile_k=3),
+                           backend="pallas")
+    assert sk.fold_traces - before == 2
 
 
 def test_unknown_signal_folds_to_empty_sketch():

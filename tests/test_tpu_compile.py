@@ -13,10 +13,10 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels import sketch as sk
-from repro.sharding.fleet import CLIENT_AXIS
+from repro.sharding.fleet import CLIENT_AXIS, replicated, ring_sharding
 
 SPEC = sk.SketchSpec()  # window 64, 16 bins, K=32: the gateway default
 #: the serving ring: 256 history slots x 100k vehicles (rounded up to a
@@ -71,13 +71,19 @@ def test_pallas_fold_compiles_for_one_chip(one_chip, window, clients):
 
 
 def test_sharded_pallas_fold_compiles_for_four_chips(four_chips):
-    fold = sk._pallas_fold_fn(four_chips, SPEC.quantile_k, SPEC.bins, 128, False)
-    x = jax.ShapeDtypeStruct(
-        (SPEC.window, CLIENTS), jnp.float32,
-        sharding=NamedSharding(four_chips, P(None, CLIENT_AXIS)),
+    # the program `sketch_ring` runs for a plane on this mesh: the ring
+    # gather and the shard_mapped kernel, fused into one jit
+    ring = jax.ShapeDtypeStruct(
+        (HISTORY, CLIENTS, SIGNALS), jnp.float32,
+        sharding=ring_sharding(four_chips),
     )
-    edges = _edges(NamedSharding(four_chips, P(None)))
-    text = jax.jit(fold).lower(x, edges).compile().as_text()
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated(four_chips))
+    edges = _edges(replicated(four_chips))
+    text = sk._ring_sketch_pallas.lower(
+        ring, scalar, scalar, edges,
+        mesh=four_chips, col=0, window=SPEC.window,
+        quantile_k=SPEC.quantile_k, n_bins=SPEC.bins, interpret=False,
+    ).compile().as_text()
     assert "tpu_custom_call" in text
     # each chip folds its own client columns: nothing crosses chips
     for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
