@@ -3,8 +3,9 @@
 Everything a cell needs is found by name: its entry in ``BENCHMARK.json``,
 its configuration file (``configs/<config>.json``) and its traffic mix
 (``traffic/<traffic>.json``). The program is reached only here: the
-simulator and gateway it builds, and the state the checks read back (the
-signal ring, the online mask, the counters).
+simulator and gateway it builds, the state the checks read back (the
+signal ring, the online mask, the counters), and its own recorder
+(`recorder`), which traced runs switch on only where a metric reader asks.
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ ROOT = HERE.parents[1]
 class Cell:
     name: str
     chips: int
+    config_name: str
     config: dict
     mix: dict
     end_to_end: list[dict]
@@ -49,6 +51,7 @@ def load_cell(name: str) -> Cell:
     return Cell(
         name=name,
         chips=int(w["chips"]),
+        config_name=w["config"],
         config=json.loads((ROOT / conf["file"]).read_text()),
         mix=json.loads((HERE / "traffic" / f"{w['traffic']}.json").read_text()),
         end_to_end=[m for m in bench["end_to_end"] if here(m)],
@@ -77,10 +80,16 @@ def fill_ticks(mix: dict) -> int:
 # --------------------------------------------------------------------- #
 # the program                                                           #
 # --------------------------------------------------------------------- #
+def _program_on_path() -> None:
+    src = str(ROOT / "src")
+    if src not in sys.path:
+        sys.path.insert(0, src)
+
+
 def build(config: dict, seed: int, devices):
     """The simulator and the gateway in front of it, as the configuration
     states them, on a ``clients`` mesh of the configuration's chips."""
-    sys.path.insert(0, str(ROOT / "src"))
+    _program_on_path()
     from repro.fleet.simulator import Backends, FleetSimulator, SimConfig
     from repro.serve.gateway import FleetGateway
     from repro.sharding.fleet import client_mesh
@@ -106,12 +115,33 @@ def ring_copy(sim) -> tuple[int, np.ndarray]:
     return plane.t, np.asarray(ring)[:, : plane.n_clients]
 
 
+#: the program's own counters, by owner, read where the instance has them
+_GATEWAY_COUNTERS = ("admitted", "stats_hits", "stats_misses")
+_PLANE_COUNTERS = ("sketch_hits", "sketch_misses", "d2h_bytes")
+_SKETCH_COUNTERS = ("fold_traces",)
+
+
 def counters(sim, gw) -> dict:
-    return {
+    from repro.kernels import sketch
+
+    out = {
         "ticks": sim.t,
         "ring_syncs": getattr(sim.plane, "ring_syncs", 0),
         **{f"served.{k}": v for k, v in gw.served.items()},
     }
+    for owner, names in ((gw, _GATEWAY_COUNTERS), (sim.plane, _PLANE_COUNTERS),
+                         (sketch, _SKETCH_COUNTERS)):
+        out.update({k: getattr(owner, k) for k in names if hasattr(owner, k)})
+    return out
+
+
+def recorder():
+    """The program's own recorder of spans and request stamps
+    (`repro.core.obs`), off until a traced run switches it on."""
+    _program_on_path()
+    from repro.core import obs
+
+    return obs
 
 
 def drain_inboxes(gw, sessions) -> None:
@@ -131,9 +161,12 @@ class Probes:
     """Host spans around each layer's entry point, written into the
     profiler's trace and kept in memory. A wrapper goes on an instance
     only where the attribute exists, so a refactor that renames a layer
-    leaves its metric empty instead of failing the run."""
+    leaves its metric empty instead of failing the run.
 
-    def __init__(self):
+    Given the program's ``recorder``, the probes switch it on, fresh, for
+    as long as they record, and read it back with `program`."""
+
+    def __init__(self, recorder=None):
         import jax
 
         self._annotate = jax.profiler.TraceAnnotation
@@ -141,11 +174,22 @@ class Probes:
         self.folds: list[dict] = []
         self.stats_misses: list[float] = []
         self._last_stats = None
+        self.recorder = recorder
+        if recorder is not None:
+            recorder.reset()
+            recorder.enable()
         self.on = True
 
     def stop(self) -> None:
         """Record nothing more: the window has closed."""
         self.on = False
+        if self.recorder is not None:
+            self.recorder.disable()
+
+    def program(self) -> dict:
+        """The program's own records of the window, or ``{}`` where the
+        recorder stayed off."""
+        return {} if self.recorder is None else self.recorder.records()
 
     def wrap(self, obj, attr: str, name: str, after=None) -> None:
         f = getattr(obj, attr, None)

@@ -2,11 +2,16 @@
 
 Each per-layer metric in ``BENCHMARK.json`` is read by
 ``metrics/<name>.py``, whose ``read(ctx)`` returns the value, or None when
-the run gave it nothing to read (the metric is then left out).
+the run gave it nothing to read (the metric is then left out). A reader
+that needs the program's own records sets ``PROGRAM = True``: a traced run
+then switches the program's recorder on for its window, and the records
+reach every reader as ``ctx.program``. Where no reader of a cell asks,
+the recorder stays off and ``ctx.program`` is ``{}``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 
 import numpy as np
@@ -23,6 +28,8 @@ class Context:
     stats_misses: list    # host seconds of each statistics computation on a miss
     counters: dict        # program counters' increase over the window
     peaks: dict           # the chip's peaks (peaks.json)
+    #: the program's records of the window (`repro.core.obs.records`)
+    program: dict = dataclasses.field(default_factory=dict)
 
     def mean_span_ms(self, name: str) -> float | None:
         s = self.spans.get(name, [])
@@ -32,9 +39,19 @@ class Context:
         return int(self.counters.get(key, 0))
 
 
-def read(name: str, ctx: Context):
+@functools.cache
+def _reader(name: str):
     path = HERE / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def wants_program(names) -> bool:
+    """Whether any of these metrics' readers needs the program's records."""
+    return any(getattr(_reader(n), "PROGRAM", False) for n in names)
+
+
+def read(name: str, ctx: Context):
+    return _reader(name).read(ctx)

@@ -17,7 +17,13 @@ The last line of standard output is one JSON object: ``correct``,
 with ``--trace 1`` its per-layer metrics from a profiler trace of the
 window), ``device``, with ``--trace 1`` a ``breakdown``, and last the
 compared numbers with their limits (``checks``). The run exits non-zero,
-with no result, where JAX finds no TPU or fewer chips than the cell asks.
+with no result, where JAX finds no TPU or fewer chips than the cell asks,
+and before any set-up where the mix sends a request kind that none of the
+configuration's modules under ``checks/`` checks (`verdict.checks_for`)
+and ``verdict.OK_ONLY`` does not name.
+
+A traced run switches the program's own recorder on for its window only
+where one of the cell's metric readers asks for it (``PROGRAM = True``).
 """
 from __future__ import annotations
 
@@ -254,6 +260,10 @@ def session(workload: str, seed: int, seconds: float, trace: bool):
     """One run up to the check: the result without ``correct`` and
     ``checks``, and the evidence the check reads."""
     cell = cells.load_cell(workload)
+    unchecked = verdict.unchecked((k["kind"] for k in cell.mix["kinds"]), cell.config)
+    if unchecked:
+        log(f"the mix sends {unchecked}, which no module under checks/ checks; no result")
+        raise SystemExit(2)
     devices = require_chip(cell.chips)[: cell.chips]
     import jax
 
@@ -281,7 +291,10 @@ def session(workload: str, seed: int, seconds: float, trace: bool):
     pre = cells.ring_copy(fleet.sim)
     probes = None
     if trace:
-        probes = cells.Probes()
+        import readings
+
+        wants = readings.wants_program(m["name"] for m in cell.per_layer)
+        probes = cells.Probes(cells.recorder() if wants else None)
         probes.install(fleet.sim, fleet.gw)
         shutil.rmtree(TRACE_DIR / workload, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
@@ -307,11 +320,8 @@ def session(workload: str, seed: int, seconds: float, trace: bool):
     peak = memory_peak(devices)
     final = cells.ring_copy(fleet.sim)
     ev = verdict.Evidence(
-        scenario=cell.config["sim"]["scenario"], seed=cells.FLEET_SEED,
-        n_clients=fleet.n, history=int(cell.config["sim"]["signal_history"]),
-        rows=verdict.sample_rows(seed, fleet.n),
-        p_leave=float(cell.config["sim"]["p_leave"]),
-        p_return=float(cell.config["sim"]["p_return"]), copies=[pre, final],
+        config=cell.config, seed=cells.FLEET_SEED, n_clients=fleet.n,
+        rows=verdict.sample_rows(seed, fleet.n), copies=[pre, final],
         answers=[(r.kind, r.params, resp) for r, resp in win["kept"]],
         unanswered=win["unanswered"], failed=win["failed"],
     )
@@ -325,14 +335,14 @@ def session(workload: str, seed: int, seconds: float, trace: bool):
               "memory_peak_bytes": peak}
     out = {"correct": None, "attempted": len(schedule), "failed": win["failed"]}
     if trace:
-        import readings
         import trace_reduce as tr
 
-        red = tr.load(tr.find_xplane(TRACE_DIR / workload))
+        program = probes.program()
+        red = tr.load(tr.find_xplane(TRACE_DIR / workload), program)
         ctx = readings.Context(
             trace=red, spans=probes.spans, folds=probes.folds,
             stats_misses=probes.stats_misses, counters=counters,
-            peaks=peaks_of(dev.device_kind))
+            peaks=peaks_of(dev.device_kind), program=program)
         metrics = {}
         for m in cell.per_layer:
             v = readings.read(m["name"], ctx)

@@ -2,7 +2,10 @@
 
 Each cell's traffic generator, the run with its reference check, the
 control and the trace reduction run here on the CPU with the look for a
-chip bypassed; the faults a cell can have must turn ``correct`` false.
+chip bypassed (`rehearse.patch`); the faults a cell can have
+(`faults.for_cell`, from its configuration and mix) must turn ``correct``
+false. Nothing here keys on a cell's name: a cell added to
+``BENCHMARK.json`` is rehearsed like the others.
 
     JAX_PLATFORMS=cpu python -m pytest -q benchmarks/chip
 """
@@ -13,40 +16,28 @@ import sys
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import cells  # noqa: E402
+import faults  # noqa: E402
 import reference  # noqa: E402
+import rehearse  # noqa: E402
 import run  # noqa: E402
 import traffic_gen  # noqa: E402
 import verdict  # noqa: E402
 
 CELLS = [w["name"] for w in cells.benchmark()["workloads"]]
 SEED = 2**33 + 12345  # wider than 32 bits: any whole number is a valid seed
-#: the rehearsal's fleet and load, small enough for the CPU
-N, RATE, SECONDS = 1024, {"stats-skew": 15.0, "dashboard": 60.0}, 1.0
+SECONDS = rehearse.SECONDS
 
 
 @pytest.fixture
 def tiny(monkeypatch):
-    """Runs on the CPU at N vehicles, for a short window."""
-    load = cells.load_cell
-
-    def small(name):
-        c = load(name)
-        c.config["sim"]["n_clients"] = N
-        c.mix["rate_per_s"] = RATE[next(w["traffic"] for w in cells.benchmark()["workloads"]
-                                        if w["name"] == name)]
-        return c
-
-    monkeypatch.setattr(cells, "load_cell", small)
-    monkeypatch.setattr(run, "require_chip", lambda chips: jax.devices())
-    monkeypatch.setattr(run, "peaks_of", lambda kind: {"hbm_bytes_per_s": 819e9})
-    return small
+    """Runs on the CPU at the rehearsal's fleet, for a short window."""
+    rehearse.patch(monkeypatch.setattr)
 
 
 def test_every_seed_sends_the_same_work():
@@ -117,97 +108,127 @@ def test_control_fails_and_program_passes(tiny, workload):
 
 
 # --------------------------------------------------------------------- #
-# faults planted under the timed path                                    #
+# where a deployment plugs in                                            #
 # --------------------------------------------------------------------- #
-def _stale_step(sim, gw):
-    """The plane step returns its state unchanged."""
-    p = sim.plane
-    p._tick_fn = lambda t, hist, off: (p._dvalues, hist)
-
-
-def _half_step(sim, gw):
-    """Half of the fleet left out of each plane step: its ring rows go
-    unwritten (NaN), and every read is served from the rest."""
-    p = sim.plane
-    orig = p._tick_fn
-    half = p.n_clients // 2
-    blank = jax.jit(lambda h, s: h.at[s, half:, :].set(jnp.nan))
-
-    def step(t, hist, off):
-        vals, h = orig(t, hist, off)
-        return vals, blank(h, t % h.shape[0])
-
-    p._tick_fn = step
-
-
-def _half_fold(sim, gw):
-    """Half of the fleet left out of each sketch fold: the statistics are
-    taken over the rest."""
-    p = sim.plane
-    orig = p.compute_sketches
-
-    def fold(name, spec, **kw):
-        sk = orig(name, spec, **kw)
-        half = sk.counts.shape[0] // 2
-        sk.counts[half:] = 0
-        sk.hists[half:] = 0
-        sk.qvals[half:] = np.nan
-        return sk
-
-    p.compute_sketches = fold
-
-
-def _altered_answer(sim, gw):
-    """One field of every answer altered where it is produced."""
-    for name in ("_read_fleet_stats", "_read_quantile", "_read_window",
-                 "_read_signal", "_read_gauges"):
-        orig = getattr(gw, name)
-
-        def alter(params, orig=orig):
-            body = dict(orig(params))
-            for k in ("mean", "value", "online"):
-                if isinstance(body.get(k), (int, float)):
-                    body[k] = body[k] + 1 if k == "online" else body[k] * (1 + 1e-3) + 1e-3
-            if body.get("values"):
-                body["values"] = body["values"][:-1] + [body["values"][-1] + 1e-3]
-            return body
-
-        setattr(gw, name, alter)
-
-
-def _wrong_churn(sim, gw):
-    """Churn takes the wrong vehicles offline: each ignition toggle lands
-    on the next vehicle in the fleet."""
-    pool, toggle = sim.pool, sim.churn._toggle
-    n = len(pool.vehicles)
-
-    def next_one(cid):
-        i = pool.vehicles[cid].metadata["index"]
-        toggle(next(c for c, v in pool.vehicles.items()
-                    if v.metadata["index"] == (i + 1) % n))
-
-    sim.churn._toggle = next_one
-
-
-#: the faults each cell can have; neither cell has an exchange between
-#: chips to leave out (one chip each)
-FAULTS = {
-    "fuel-100k.stats-skew": [_stale_step, _half_fold, _altered_answer],
-    "dropout-100k.dashboard": [_stale_step, _half_step, _altered_answer, _wrong_churn],
+#: every limit the accepted cells are held to, as PERF.md sets them
+ACCEPTED_LIMITS = {
+    "ring_gap": 1e-3, "ring_nan_miss": 0, "stats_miss": 0, "stats_mean_gap": 3e-3,
+    "stats_var_gap": 1e-2, "window_miss": 0, "signal_gap": 1e-3, "gauge_miss": 0,
+    "unanswered": 0, "failed": 0,
 }
 
 
-@pytest.mark.parametrize("workload,fault", [(w, f) for w in CELLS for f in FAULTS[w]],
-                         ids=[f"{w}-{f.__name__[1:]}" for w in CELLS for f in FAULTS[w]])
+def test_merged_limits_keep_every_accepted_limit():
+    """The check modules' limits, merged, hold each accepted limit key for
+    key, in its own type (a count's limit stays the integer 0); a new
+    check module may only add numbers of its own."""
+    got = {k: repr(verdict.LIMITS.get(k)) for k in ACCEPTED_LIMITS}
+    assert got == {k: repr(v) for k, v in ACCEPTED_LIMITS.items()}
+    assert {k: m.__name__ for k, m in verdict.checks_for({}).items()} == {
+        "fleet_stats": "checks.stats", "quantile": "checks.stats",
+        "window": "checks.window", "signal": "checks.signal", "gauges": "checks.gauges"}
+
+
+def test_a_kind_with_no_check_cannot_run(tiny, monkeypatch):
+    """A mix that sends a kind no check module covers, and that OK_ONLY
+    does not name, ends the run with a non-zero code before set-up."""
+    assert verdict.unchecked(["platform", "gauges", "window"], {}) == []
+    assert verdict.unchecked(["cohort_stats", "gauges"], {}) == ["cohort_stats"]
+    load = cells.load_cell
+
+    def with_new_kind(name):
+        c = load(name)
+        c.mix["kinds"].append({"kind": "cohort_stats", "share": 0.1})
+        return c
+
+    def no_setup(*a, **k):
+        pytest.fail("set-up began")
+
+    monkeypatch.setattr(cells, "load_cell", with_new_kind)
+    monkeypatch.setattr(run, "require_chip", no_setup)
+    monkeypatch.setattr(cells, "build", no_setup)
+    with pytest.raises(SystemExit) as stop:
+        run.main(["--workload", CELLS[0], "--seed", str(SEED), "--seconds", "1"])
+    assert stop.value.code not in (0, None)
+
+
+def test_a_configurations_own_check_reaches_only_its_answers(monkeypatch):
+    """A check module that a configuration names checks that
+    configuration's answers, never another's; one that takes a kind the
+    common checks hold is refused."""
+    import types
+
+    own = types.SimpleNamespace(__name__="checks.platform_own", KINDS=("platform",),
+                                LIMITS={}, check=lambda ev, items, seed, lower=False: {})
+    twin = types.SimpleNamespace(__name__="checks.window_twin", KINDS=("window",),
+                                 LIMITS={}, check=own.check)
+    monkeypatch.setitem(verdict.MODULES, "platform_own", own)
+    monkeypatch.setitem(verdict.MODULES, "window_twin", twin)
+    for w in CELLS:
+        config = cells.load_cell(w).config
+        assert set(verdict.checks_for(config)) == {
+            "fleet_stats", "quantile", "window", "signal", "gauges"}, w
+    assert verdict.checks_for({"checks": ["platform_own"]})["platform"] is own
+    with pytest.raises(ValueError):
+        verdict.checks_for({"checks": ["window_twin"]})
+    with pytest.raises(ValueError):
+        verdict.checks_for({"checks": ["no_such_check"]})
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_recorder_is_on_only_where_a_reader_asks(tiny, monkeypatch, workload):
+    """A traced run switches the program's recorder on exactly where one of
+    the cell's metric readers declares ``PROGRAM``; no accepted reader does."""
+    import readings
+
+    obs = cells.recorder()
+    calls = []
+    monkeypatch.setattr(obs, "enable", lambda: calls.append("enable"))
+    out = run.run(workload, SEED + 4, SECONDS, True)
+    asks = readings.wants_program(m["name"] for m in cells.load_cell(workload).per_layer)
+    assert calls == (["enable"] if asks else [])
+    assert out["correct"], out["checks"]
+
+
+def _cell(kinds, p_leave, config_name="no-such-config"):
+    return cells.Cell(name="synthetic", chips=1, config_name=config_name,
+                      config={"sim": {"p_leave": p_leave}},
+                      mix={"kinds": [{"kind": k, "share": 1.0} for k in kinds]},
+                      end_to_end=[], per_layer=[])
+
+
+def test_faults_follow_the_configuration_and_mix():
+    """The rule gives the statistics mix without churn, and the dashboard
+    mix with and without churn, the faults each was rehearsed with."""
+    def names(cell):
+        return [f.__name__ for f in faults.for_cell(cell)]
+
+    dashboard = ["gauges", "platform", "signal", "window"]
+    assert names(_cell(["fleet_stats", "quantile"], 0.0)) == [
+        "stale_step", "half_fold", "altered_answer"]
+    assert names(_cell(dashboard, 0.0005)) == [
+        "stale_step", "half_step", "altered_answer", "wrong_churn"]
+    assert names(_cell(dashboard, 0.0)) == ["stale_step", "half_step", "altered_answer"]
+
+
+def test_rehearsal_rate_follows_the_mix():
+    """The mix's own rate, held between 15 and 60 req/s: the statistics mix
+    (1.6 req/s) rehearses at 15, the dashboard (6400 req/s) at 60."""
+    assert rehearse.cpu_rate({"rate_per_s": 1.6}) == 15.0
+    assert rehearse.cpu_rate({"rate_per_s": 6400.0}) == 60.0
+    assert rehearse.cpu_rate({"rate_per_s": 25.0}) == 25.0
+
+
+# --------------------------------------------------------------------- #
+# faults planted under the timed path                                    #
+# --------------------------------------------------------------------- #
+FAULTS = [(w, f) for w in CELLS for f in faults.for_cell(cells.load_cell(w))]
+
+
+@pytest.mark.parametrize("workload,fault", FAULTS,
+                         ids=[f"{w}-{f.__name__}" for w, f in FAULTS])
 def test_fault_turns_correct_false(tiny, monkeypatch, workload, fault):
-    build = cells.build
-
-    def broken(config, seed, devices):
-        sim, gw = build(config, seed, devices)
-        fault(sim, gw)
-        return sim, gw
-
-    monkeypatch.setattr(cells, "build", broken)
+    rehearse.plant(monkeypatch.setattr, fault)
     out = run.run(workload, SEED + 3, SECONDS, False)
     assert out["correct"] is False, out["checks"]
 

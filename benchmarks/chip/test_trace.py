@@ -75,3 +75,72 @@ def test_top_ops_are_sorted_and_inside_the_busy_time(red):
     assert sum(secs) <= sum(tr.length(np.stack([d.ops.start, d.ops.end], 1))
                             for d in red.devices) * 1e-9 * (1 + 1e-9)
     assert all(":" in name for name, _ in ops)
+
+
+def test_program_spans_take_idle_first_where_the_trace_has_them():
+    """Idle inside a program span goes to it before the harness span
+    around it; without program spans the harness spans get it as before."""
+    dev = tr.Device(tr.Ops(np.asarray([0.0, 90.0]), np.asarray([10.0, 100.0]), ["a", "b"]),
+                    tr.Ops(np.zeros(0), np.zeros(0), []))
+    harness = {"bench.window": iv((0, 100)), "bench.tick": iv((10, 90))}
+    red = tr.Reduced((0.0, 100.0), [dev], dict(harness))
+    assert tr.idle_by_span(red) == [["bench.tick", 80e-9]]
+    program = {"spans": {"name": np.asarray(["gateway.admit", "plane.ring_sync"]),
+                         "parent": np.asarray([-1, 0])}}
+    assert tr.program_order(program) == ("plane.ring_sync", "gateway.admit")
+    red = tr.Reduced((0.0, 100.0), [dev], {**harness, "plane.ring_sync": iv((20, 50)),
+                                           "gateway.admit": iv((15, 60))},
+                     tr.program_order(program))
+    got = dict(tr.idle_by_span(red))
+    assert got == pytest.approx({"plane.ring_sync": 30e-9, "gateway.admit": 15e-9,
+                                 "bench.tick": 35e-9})
+
+
+def test_program_order_follows_the_recorders_nesting():
+    """Innermost first by the ``parent`` column, whatever the names: a
+    name nested deeper anywhere goes ahead; ties go by name."""
+    program = {"spans": {
+        "name": np.asarray(["cohort.tick", "cohort.merge", "cohort.scan", "jax.compile",
+                            "cohort.tick", "cohort.scan", "cohort.merge"]),
+        "parent": np.asarray([-1, 0, 1, -1, -1, 4, 4])}}
+    assert tr.program_order(program) == (
+        "cohort.scan", "cohort.merge", "cohort.tick", "jax.compile")
+    assert tr.program_order({}) == ()
+
+
+def test_a_program_span_of_any_name_reaches_the_breakdown(tmp_path):
+    """A span the program records under a name the harness has never seen
+    is kept from a live trace and charged its idle time, innermost first,
+    once the program's records name it; without them it is left out."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    import cells
+
+    obs = cells.recorder()
+    double = jax.jit(lambda x: x * 2)
+    x = jnp.ones(1024)
+    double(x).block_until_ready()
+    obs.reset()
+    obs.enable()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with jax.profiler.TraceAnnotation("bench.window"):
+                with obs.span("cohort.tick"):
+                    with obs.span("cohort.segmented_merge"):
+                        time.sleep(0.05)
+                    double(x).block_until_ready()
+                    time.sleep(0.02)
+    finally:
+        obs.disable()
+    program = obs.records()
+    obs.reset()
+    path = tr.find_xplane(tmp_path)
+    red = tr.load(path, program)
+    assert red.program_order == ("cohort.segmented_merge", "cohort.tick")
+    assert {"cohort.tick", "cohort.segmented_merge"} <= set(red.spans)
+    gaps = dict(tr.idle_by_span(red))
+    assert gaps["cohort.segmented_merge"] > 0.04 and gaps["cohort.tick"] > 0.01
+    assert not {"cohort.tick", "cohort.segmented_merge"} & set(tr.load(path).spans)

@@ -5,7 +5,8 @@ Device operations are the events of each TPU core's ``XLA Ops`` line (on
 a trace without a TPU, the CPU backend's per-op events stand in, so the
 reduction can be rehearsed). Host spans are the ``bench.*`` annotations
 the harness writes around its own calls and around each layer's entry
-point; ``bench.window`` marks the measured window.
+point, and the program's own spans where its recorder was on and its
+records name them; ``bench.window`` marks the measured window.
 
 Times are nanoseconds on the trace's clock; every interval list is a
 sorted ``(k, 2)`` array of disjoint ``[start, end)`` pairs once merged.
@@ -43,6 +44,8 @@ class Reduced:
     window: tuple[float, float]
     devices: list[Device]
     spans: dict[str, np.ndarray]
+    #: the program's span names, innermost first (`program_order`)
+    program_order: tuple[str, ...] = ()
 
     @property
     def window_s(self) -> float:
@@ -73,8 +76,30 @@ def _op(name: str) -> str:
     return name.split(" = ", 1)[0]
 
 
-def load(path: Path) -> Reduced:
+def program_order(program: dict) -> tuple[str, ...]:
+    """The span names of the program's records (`repro.core.obs.records`),
+    innermost first: by the deepest any of a name's spans sits in the
+    recorder's ``parent`` column, then by name."""
+    spans = program.get("spans")
+    if spans is None or not len(spans["name"]):
+        return ()
+    parent = np.asarray(spans["parent"])
+    depth = np.zeros(len(parent), np.int64)
+    for i, p in enumerate(parent):  # a parent opens before its children
+        depth[i] = depth[p] + 1 if p >= 0 else 0
+    deepest: dict[str, int] = {}
+    for name, d in zip(np.asarray(spans["name"]).tolist(), depth.tolist()):
+        deepest[name] = max(d, deepest.get(name, d))
+    return tuple(sorted(deepest, key=lambda n: (-deepest[n], n)))
+
+
+def load(path: Path, program: dict | None = None) -> Reduced:
+    """The trace at ``path``, keeping the harness's spans and, given the
+    program's records, the host annotations that they name."""
     from jax.profiler import ProfileData
+
+    order = program_order(program or {})
+    named = set(order)
 
     data = ProfileData.from_file(str(path))
     spans: dict[str, list] = {}
@@ -85,7 +110,7 @@ def load(path: Path) -> Reduced:
         for line in plane.lines:
             for ev in line.events:
                 name, t, d = ev.name, ev.start_ns, ev.duration_ns
-                if name.startswith("bench."):
+                if name.startswith("bench.") or name in named:
                     spans.setdefault(name, []).append((t, t + d))
                 elif is_tpu and line.name == "XLA Ops":
                     ops.append((t, t + d, _op(name)))
@@ -102,7 +127,7 @@ def load(path: Path) -> Reduced:
         raise ValueError("trace has no bench.window span")
     w = spans["bench.window"][0]
     return Reduced((w[0], w[1]), devices,
-                   {k: np.asarray(sorted(v), np.float64) for k, v in spans.items()})
+                   {k: np.asarray(sorted(v), np.float64) for k, v in spans.items()}, order)
 
 
 def _ops(rows: list) -> Ops:
@@ -242,12 +267,14 @@ def top_ops(red: Reduced, k: int = 10) -> list[list]:
 
 
 def idle_by_span(red: Reduced, k: int = 10) -> list[list]:
-    """Idle device seconds in the window, charged to the innermost harness
-    span the host was in (``host.other`` outside all of them)."""
+    """Idle device seconds in the window, charged to the innermost span the
+    host was in: the program's own where the trace has them, then the
+    harness's (``host.other`` outside all of them)."""
+    order = [n for n in red.program_order if n in red.spans] + list(SPAN_ORDER)
     acc: dict[str, float] = {}
     for d in red.devices:
         idle = subtract(_window(red), busy(red, d))
-        for name in SPAN_ORDER:
+        for name in order:
             s = union(spans(red, name))
             hit = intersect(idle, s)
             acc[name] = acc.get(name, 0.0) + length(hit) * 1e-9 / len(red.devices)
