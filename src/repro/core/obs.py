@@ -3,8 +3,13 @@
 The gateway, the engine, the signal planes and the simulator open a
 `span` around each layer's work (``gateway.tick``, ``engine.drain``,
 ``gateway.admit``, ``gateway.stats``, ``gateway.merge``, ``plane.fold``,
-``plane.step``, ``plane.ring_sync``, ``service.sweep``), and the gateway
-stamps each request when it is submitted, admitted and answered.
+``plane.step``, ``plane.ring_sync``, ``service.sweep``; under Linear
+Road ``plane.tolls``, the toll step after the plane step, and
+``gateway.lr_read``, a balance, expenditure or notice read), and the
+gateway stamps each request when it is submitted, admitted and answered.
+Counters are not kept here: each layer's instance holds its own (the
+toll state's ``crossings``, ``tolls_charged``, ``accidents`` and
+``alerts``, for example).
 
 Off (the default), `span` returns one preallocated no-op object after a
 single module-global bool check, and the stamps return at the same check:

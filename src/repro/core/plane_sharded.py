@@ -260,6 +260,10 @@ class ShardedSignalPlane(FleetSignalPlane):
             self._values_dirty = True
             self._hist_dirty = True
 
+    def device_ring(self) -> jax.Array:
+        """The donated ring on the device, `(history, capacity, n_signals)`."""
+        return self._dhist
+
     def block_until_ready(self) -> None:
         """Wait for in-flight device work (benchmark fairness hook)."""
         jax.block_until_ready((self._dvalues, self._dhist))

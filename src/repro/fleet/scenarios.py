@@ -17,7 +17,11 @@ the whole fleet at once — each scenario is a pure function
 * ``road-grade`` — the legacy constant per-vehicle grade (exactly
                    ``0.01 * (i % 7)``), time-invariant: the simulator's
                    default, preserving the fault-free == lossy aggregate
-                   property that the resiliency tests pin down.
+                   property that the resiliency tests pin down;
+* ``linear-road`` — Linear Road's position reports (VLDB 2004): trips
+                   on L expressways with jams and accidents, in columns
+                   of their own (`repro.fleet.linear_road.COLUMNS`),
+                   every value an integer computed in integer arithmetic.
 
 Determinism and row stability: per-client randomness is derived with
 ``fold_in(key(seed), client_index)`` and per-tick noise with a further
@@ -39,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.signals import FleetSignalPlane, ScriptedSignalBroker
+from repro.fleet import linear_road as lr
 
 #: canonical signal names every scenario publishes, column order fixed
 SIGNALS: tuple[str, ...] = (
@@ -53,7 +58,7 @@ _HIGHWAY, _URBAN, _IDLE = 0, 1, 2
 #: regime mix of the ``mixed`` fleet
 _MIX = (0.45, 0.35, 0.20)
 
-SCENARIOS = ("road-grade", "highway", "urban", "idle", "mixed")
+SCENARIOS = ("road-grade", "highway", "urban", "idle", "mixed", "linear-road")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,11 +71,21 @@ class Scenario:
 
     name: str
     seed: int = 0
-    signals: tuple[str, ...] = SIGNALS
+    #: the scenario's columns: `SIGNALS`, or Linear Road's own
+    signals: tuple[str, ...] | None = None
+    #: Linear Road's L (``linear-road`` only)
+    expressways: int = 2
+
+    def __post_init__(self):
+        if self.signals is None:
+            own = lr.COLUMNS if self.name == "linear-road" else SIGNALS
+            object.__setattr__(self, "signals", own)
 
     def step_fn(self, n_clients: int) -> Callable[[jax.Array], jax.Array]:
         if self.name == "road-grade":
             return _constant_road_grade_step(n_clients)
+        if self.name == "linear-road":
+            return _linear_road_step(n_clients, self.seed, self.expressways)
         if self.name not in SCENARIOS:
             raise ValueError(
                 f"unknown scenario {self.name!r}; pick one of {SCENARIOS}"
@@ -129,10 +144,11 @@ def build_plane(
     history: int = 256,
     plane: str = "host",
     mesh=None,
+    expressways: int = 2,
 ) -> FleetSignalPlane:
     """The one-liner the simulator uses. ``plane`` picks the single-host
     columnar plane (default) or the device-sharded plane."""
-    scen = Scenario(name, seed)
+    scen = Scenario(name, seed, expressways=expressways)
     if plane == "host":
         return scen.plane(n_clients, history=history)
     if plane == "sharded":
@@ -245,6 +261,98 @@ def _drive_cycle_step(
         temp = ambient + (target - ambient) * (1.0 - jnp.exp(-tf / tau))
 
         return jnp.stack([speed, fuel, grade, temp], axis=1).astype(jnp.float32)
+
+    return step
+
+
+# --------------------------------------------------------------------- #
+# Linear Road: trips, jams and accidents in integer arithmetic           #
+# --------------------------------------------------------------------- #
+#: ticks of a trip: the entrance-lane report, travel, the exit-lane report
+LR_TRIP = 40
+#: trips per jam epoch: each (xway, dir) jam moves every 120 ticks (1 h)
+LR_EPOCH_TRIPS = 3
+#: segments a jam covers
+LR_JAM = 15
+#: vehicles are numbered in blocks; the first LR_QUEUE of each block crawl
+#: in a jam's queue, one segment of the jam per block
+LR_BLOCK, LR_QUEUE = 1024, 256
+#: the accident pairs: vehicles 2p and 2p+1 of this range share their trip
+#: timing, and one trip in LR_ACCIDENT_ODDS of a pair is a crash in the
+#: queue of block 0
+LR_PAIRS = (256, 512)
+LR_ACCIDENT_ODDS = 8
+
+
+def _linear_road_step(n: int, seed: int, expressways: int) -> Callable[[jax.Array], jax.Array]:
+    """Linear Road position reports, `t -> (n, 6)` f32 in `lr.COLUMNS`
+    order; vehicle v's row is a closed form of (seed, v, t).
+
+    Each vehicle makes back-to-back trips of `LR_TRIP` ticks, shifted by a
+    seeded phase: an entrance-lane report, travel in one lane (1-3), an
+    exit-lane report, then the next trip's entrance at the next tick, so
+    every vehicle reports every tick (the peak hour's steady state). Each
+    trip draws its expressway, direction, lane and speeds. Each (xway,
+    dir) has a jam of `LR_JAM` segments per epoch. A free trip drives at
+    45-75 mph, through a jam at 20-35 mph. A queue trip crawls at 1-3
+    mph inside its block's jam segment for the whole trip, so a jam's
+    occupancy is a share of the fleet at any fleet size. An accident trip
+    is a queue trip of a pair that stops at one position for 3-12 ticks.
+    A reported speed is the one that carries the vehicle to its next
+    position: ``x(tau + 1) = x(tau) + 44 * speed(tau)`` feet, in the
+    direction's own coordinate (position 527,999 - x westbound)."""
+
+    def step(t: jax.Array) -> jax.Array:
+        v = jnp.arange(n, dtype=jnp.int32)
+        pair = (v >= LR_PAIRS[0]) & (v < LR_PAIRS[1])
+        who = jnp.where(pair, v - v % 2, v)
+        s = t + (lr.draw(seed, who, 1) % LR_TRIP).astype(jnp.int32)
+        k, tau = s // LR_TRIP, s % LR_TRIP
+        accident = pair & (lr.draw(seed, who, k, 2) % LR_ACCIDENT_ODDS == 0)
+        queue = (v % LR_BLOCK < LR_QUEUE) | accident
+        trip = jnp.where(accident, who, v)
+
+        def pick(salt, lo, hi):  # a whole number in [lo, hi] per trip
+            return lo + (lr.draw(seed, trip, k, salt) % (hi - lo + 1)).astype(jnp.int32)
+
+        xway = pick(3, 0, expressways - 1)
+        dirn = pick(4, 0, 1)
+        lane = pick(5, 1, 3)
+        j0 = (lr.draw(seed, xway, dirn, k // LR_EPOCH_TRIPS, 6)
+              % (lr.SEGMENTS - LR_JAM + 1)).astype(jnp.int32)
+        start = pick(7, 0, 2**30)
+
+        # queue and accident trips, inside one jam segment
+        vq = pick(8, 1, 3)
+        dq = lr.FT_PER_MPH_TICK * vq
+        qseg = j0 + (v // LR_BLOCK) % LR_JAM
+        stop = pick(9, 3, 12)
+        tau_s = pick(10, 1, LR_TRIP - 2 - 12) + (12 - stop)
+        halted = accident & (tau >= tau_s) & (tau < tau_s + stop)
+        moved = jnp.where(accident, jnp.minimum(tau, tau_s) + jnp.maximum(tau - tau_s - stop, 0),
+                          tau)
+        xq = qseg * lr.SEGMENT_FT + start % (lr.SEGMENT_FT - dq * (LR_TRIP - 1)) + dq * moved
+        speed_q = jnp.where(halted, 0, vq)
+
+        # free trips: fast, slowed through the jam [a, b)
+        vf, vs = pick(11, 45, 75), pick(12, 20, 35)
+        df, dz = lr.FT_PER_MPH_TICK * vf, lr.FT_PER_MPH_TICK * vs
+        a, b = j0 * lr.SEGMENT_FT, (j0 + LR_JAM) * lr.SEGMENT_FT
+        x0 = start % (lr.ROAD_FT - df * (LR_TRIP - 1))
+        t1 = jnp.where(x0 >= a, 0, (a - x0 + df - 1) // df)  # ticks to reach the jam
+        x1 = x0 + df * t1
+        t2 = jnp.where(x1 >= b, 0, (b - x1 + dz - 1) // dz)  # ticks inside it
+        x2 = x1 + dz * t2
+        xf = jnp.where(tau <= t1, x0 + df * tau,
+                       jnp.where(tau <= t1 + t2, x1 + dz * (tau - t1), x2 + df * (tau - t1 - t2)))
+        speed_f = jnp.where((tau >= t1) & (tau < t1 + t2), vs, vf)
+
+        x = jnp.where(queue, xq, xf)
+        speed = jnp.where(queue, speed_q, speed_f)
+        lane = jnp.where(tau == 0, lr.ENTRY_LANE, jnp.where(tau == LR_TRIP - 1, lr.EXIT_LANE, lane))
+        pos = jnp.where(dirn == 0, x, lr.ROAD_FT - 1 - x)
+        cols = (speed, xway, dirn, lane, pos // lr.SEGMENT_FT, pos)
+        return jnp.stack(cols, axis=1).astype(jnp.float32)
 
     return step
 

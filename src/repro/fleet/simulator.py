@@ -22,7 +22,9 @@ Time is an integer tick. One `tick()`:
 2. advances the broker clock, releasing delayed messages (`Broker.advance`);
 3. advances the fleet's signals — ONE columnar `FleetSignalPlane` step
    (a jit'd drive-cycle scenario from `repro.fleet.scenarios`) instead of
-   the old O(n_clients × n_signals) per-vehicle iterator loop;
+   the old O(n_clients × n_signals) per-vehicle iterator loop — and,
+   under the ``linear-road`` scenario, runs Linear Road's toll step over
+   the ring (`repro.fleet.linear_road.Tolls`);
 4. services the fleet's sync loops: `EngineService` under the engine
    (heap-fed refills + wakes, only due/woken clients touched), the
    numpy-masked `FleetServiceScheduler`, or the original
@@ -65,6 +67,7 @@ from repro.fleet.churn import make_churn
 from repro.fleet.elastic import FleetPool
 from repro.fleet.engine import CalendarService, EngineService, EventEngine
 from repro.fleet.federated import FedConfig
+from repro.fleet.linear_road import Tolls
 from repro.fleet.metrics import FleetMetrics, RoundMetrics
 from repro.fleet.rounds import FederatedDriver
 from repro.fleet.scenarios import build_plane
@@ -169,6 +172,9 @@ class SimConfig:
     scenario: str = "road-grade"
     #: plane history ring depth (backs `autospada.get_signal_window`)
     signal_history: int = 256
+    #: Linear Road's L, the expressways the ``linear-road`` scenario's
+    #: vehicles drive and its toll step keeps statistics for
+    expressways: int = 2
     # -- broker faults -------------------------------------------------- #
     p_drop: float = 0.0        # QoS-0 notification drop probability
     p_duplicate: float = 0.0   # QoS-1 redelivery probability
@@ -265,8 +271,18 @@ class FleetSimulator:
                 history=cfg.signal_history,
                 plane=b.plane.value,
                 mesh=mesh,
+                expressways=cfg.expressways,
             )
         )
+        #: Linear Road's toll state, run after each plane step over the
+        #: sharded plane's ring; only the ``linear-road`` scenario has one
+        self.tolls = None
+        if self.plane is not None and cfg.scenario == "linear-road":
+            if b.plane is not PlaneBackend.SHARDED:
+                raise ValueError("the linear-road scenario's toll step reads the "
+                                 "ring on the device: Backends(plane='sharded')")
+            self.tolls = Tolls(self.plane.device_ring().shape[1], cfg.seed,
+                               cfg.expressways, self.plane.mesh)
         self.pool = FleetPool(
             self.store,
             self.broker,
@@ -378,6 +394,8 @@ class FleetSimulator:
         #    Scripted signals keep the historical behaviour: a powered-off
         #    vehicle's iterators pause until the ignition returns.
         self.pool.tick_signals(online_only=True)
+        if self.tolls is not None:
+            self.tolls.step(self.plane.device_ring(), self.plane.t)
         # 4. bounded sync-loop service: O(runnable) via the event-driven
         #    scheduler (or the dense O(N) oracle — identical interleaving)
         with obs.span("service.sweep"):

@@ -60,6 +60,7 @@ from repro.core import obs
 from repro.fleet.analytics import AnalyticsConfig, AnalyticsDriver
 from repro.fleet.engine import PHASE_ADMIT
 from repro.fleet.federated import FedConfig
+from repro.fleet.linear_road import LAV_TICKS
 from repro.fleet.rounds import FederatedDriver
 from repro.kernels.ops import (
     merge_histograms,
@@ -71,10 +72,11 @@ from repro.kernels.sketch import SketchSpec
 if TYPE_CHECKING:  # pragma: no cover
     from repro.fleet.simulator import FleetSimulator
 
-#: request kinds served synchronously at admission
+#: request kinds served synchronously at admission; the last three are
+#: Linear Road's, answered from the toll state (`repro.fleet.linear_road`)
 READ_KINDS = (
     "gauges", "platform", "progress", "signal", "window", "fleet_stats",
-    "quantile",
+    "quantile", "account_balance", "daily_expenditure", "toll_notice",
 )
 #: request kinds that commit an assignment and answer when it closes
 SUBMIT_KINDS = ("submit_round", "submit_window")
@@ -531,6 +533,40 @@ class FleetGateway:
             "count": st.count,
             "value": st.quantile(qq),
         }
+
+    # -- Linear Road: one vehicle's toll state, gathered on the device --- #
+    def _tolls(self):
+        tolls = self.sim.tolls
+        if tolls is None:
+            raise ValueError("this fleet keeps no tolls (scenario 'linear-road' only)")
+        return tolls
+
+    def _vehicle(self, params: dict) -> int:
+        row = self._row(params["client"])
+        if not 0 <= row < self._plane().n_clients:
+            raise ValueError(f"no vehicle {params['client']!r} in this fleet")
+        return row
+
+    def _read_account_balance(self, params: dict) -> dict:
+        """Type 2: balance and the tick of the last charge."""
+        with obs.span("gateway.lr_read"):
+            return self._tolls().balance(self._vehicle(params))
+
+    def _read_daily_expenditure(self, params: dict) -> dict:
+        """Type 3: one day's spend on one expressway, day 1..69."""
+        with obs.span("gateway.lr_read"):
+            return self._tolls().expenditure(
+                self._vehicle(params), int(params["xway"]), int(params["day"]))
+
+    def _read_toll_notice(self, params: dict) -> dict:
+        """The vehicle's latest notice: tick, segment, toll, LAV, count and
+        the segment of the accident it was alerted to (-1: none). A
+        ``window`` given must be the LAV window the tolls use, in ticks."""
+        window = int(params.get("window", LAV_TICKS))
+        if window != LAV_TICKS:
+            raise ValueError(f"tolls average speed over {LAV_TICKS} ticks, not {window}")
+        with obs.span("gateway.lr_read"):
+            return self._tolls().notice(self._vehicle(params))
 
     # ------------------------------------------------------------------ #
     # submission handlers (deferred responses)                           #

@@ -141,6 +141,39 @@ def test_layer_spans_nest(tracer, plane, child, parents):
     assert np.all(sp["t1"][idx] <= sp["t1"][sp["parent"][idx]])
 
 
+def drive_tolls(ticks=4):
+    """A Linear Road fleet, its toll step after every plane step, and its
+    three reads each tick."""
+    sim = FleetSimulator(SimConfig(
+        n_clients=64, seed=7, scenario="linear-road", signal_history=12,
+        backends=Backends(plane="sharded"),
+    ))
+    gw = FleetGateway(sim)
+    s = gw.session("motorist")
+    for k in range(ticks):
+        s.ask("account_balance", client=k)
+        s.ask("toll_notice", client=k + 1)
+        s.ask("daily_expenditure", client=k, xway=k % 2, day=k + 1)
+        gw.tick()
+    return gw
+
+
+def test_toll_spans_nest_and_record_nothing_while_off(tracer):
+    gw = drive_tolls()
+    rec = obs.records()
+    sp = rec["spans"]
+    for child, parent, n in (("plane.tolls", "gateway.tick", gw.sim.t),
+                             ("gateway.lr_read", "gateway.admit", 3 * gw.sim.t)):
+        idx = spans_named(rec, child)
+        assert len(idx) == n, child
+        assert set(sp["name"][sp["parent"][idx]]) == {parent}
+        assert np.all(sp["t1"][idx] <= sp["t1"][sp["parent"][idx]])
+    obs.disable()
+    obs.reset()
+    drive_tolls()
+    assert len(obs.records()["spans"]["name"]) == 0
+
+
 def test_ring_sync_is_a_span_only_when_it_copies(tracer):
     gw = make_gateway("sharded")
     s = gw.session("ana")

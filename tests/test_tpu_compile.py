@@ -3,8 +3,8 @@ described TPU v5e (no chip attached).
 
 The TPU compiler refuses what interpret mode accepts (unsupported Mosaic
 primitives, unaligned slices, programs that do not fit device memory),
-so these compiles guard the sketch kernel at its serving widths on every
-test run. The topology is described inside a module-scoped fixture, never
+so these compiles guard the sketch kernel and Linear Road's toll step at
+their serving widths on every test run. The topology is described inside a module-scoped fixture, never
 at import: only one process at a time may load the TPU library, and the
 tests of this file must be the same on every pytest-xdist worker.
 """
@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro.fleet import linear_road as lr
 from repro.kernels import sketch as sk
 from repro.sharding.fleet import CLIENT_AXIS, replicated, ring_sharding
 
@@ -106,3 +107,27 @@ def test_ring_sketch_twin_fits_one_chip(one_chip):
     )
     assert mem.argument_size_in_bytes >= HISTORY * CLIENTS * SIGNALS * 4
     assert used < V5E_HBM_BYTES
+
+
+#: Linear Road at L = 2: 102,000 vehicles (a multiple of 4 chips), the
+#: rules' 12 ring slots, the scenario's 6 columns
+LR_CLIENTS, LR_XWAYS = 102_000, 2
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_toll_step_compiles(topo, chips):
+    mesh = Mesh(topo.devices[:chips], (CLIENT_AXIS,))
+    ring = jax.ShapeDtypeStruct((lr.HISTORY, LR_CLIENTS, len(lr.COLUMNS)), jnp.float32,
+                                sharding=ring_sharding(mesh))
+    sh = lr.state_shardings(mesh)
+    state = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh[k])
+             for k, v in lr.initial_state(LR_CLIENTS).items()}
+    tick = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated(mesh))
+    compiled = jax.jit(
+        lr.toll_step, static_argnames="expressways", out_shardings=sh, donate_argnums=(0,),
+    ).lower(state, ring, tick, expressways=LR_XWAYS).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= LR_CLIENTS * (lr.HISTORY * len(lr.COLUMNS) + 9) * 4 // chips
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES // 16
+    # on a mesh the grouped sums are reduced across the client shards
+    assert ("all-reduce" in compiled.as_text()) == (chips > 1)
